@@ -41,9 +41,9 @@ if __package__ in (None, ""):  # direct `python benchmarks/sharded_hierarchy.py`
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from benchmarks.common import emit, write_bench_json
-from repro.compat import make_mesh
 from repro.core import (Prefetcher, ShardedFeatureStore, TieredFeatureStore,
                         TopologySpec, WorkloadGenerator, compute_fap,
                         quiver_placement)
@@ -119,7 +119,7 @@ def run(dry_run: bool = False) -> dict:
     n_req = 8 if dry_run else 48
     sizes = (4, 16, 48) if dry_run else (8, 32, 128)
     world = len(jax.devices())
-    mesh = make_mesh((world,), ("x",))
+    mesh = jax.make_mesh((world,), ("x",), axis_types=(AxisType.Auto,))
     spill = tempfile.NamedTemporaryFile(suffix=".spill", delete=False)
     spill.close()
     spill_dir = tempfile.mkdtemp(prefix="shard_spill_")

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
 
 
 def _bag_kernel(ids_ref, w_ref, table_ref, o_ref, acc_ref, cnt_ref, *,
@@ -80,7 +79,7 @@ def embedding_bag_pallas(table: jnp.ndarray, ids: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), table.dtype),
         scratch_shapes=[pltpu.VMEM((block_rows, d), jnp.float32),
                         pltpu.VMEM((block_rows, 128), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(ids_p, w_p, table)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
 
 
 def _spmm_kernel(ids_ref, w_ref, feat_ref, o_ref, acc_ref, *, dmax: int,
@@ -84,7 +83,7 @@ def segment_spmm_pallas(ids: jnp.ndarray, feat: jnp.ndarray,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * block_rows, d), feat.dtype),
         scratch_shapes=[pltpu.VMEM((block_rows, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(ids_p, w_p, feat)

@@ -32,12 +32,13 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
 from repro.core import (Prefetcher, ShardedFeatureStore, TieredFeatureStore,
                         TopologySpec, WorkloadGenerator, compute_fap,
                         compute_psgs, quiver_placement)
@@ -49,6 +50,23 @@ from repro.serving import (AdaptiveConfig, AdaptiveController,
                            ModelRegistry, ServingEngine, ServingGateway,
                            ShardedExecutor, StaticScheduler,
                            build_model_entry, calibrate_executors)
+
+# checkout root (src/repro/launch/serve.py → four levels up)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` where it is set (JAX reads it itself),
+    else the fixed ``.jax_cache`` at the checkout root — a path that never
+    moves, so a later run finds what an earlier one compiled."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # --models presets: hidden layer widths of the GraphSAGE variant each model
 # serves (all share the graph, feature store and samplers — only the model
@@ -133,7 +151,7 @@ def build_sharded_store(graph, feats, fap, *, hot_frac: float = 0.25,
         raise SystemExit(
             "--sharded needs ≥2 devices; on CPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=8")
-    mesh = make_mesh((world,), ("x",))
+    mesh = jax.make_mesh((world,), ("x",), axis_types=(AxisType.Auto,))
     # rebuild a placement whose warm tier is sharded over the real mesh;
     # size HBM (hot+warm) to cover every node so the sharded store —
     # which serves only the HBM tiers — is exact for any batch
@@ -482,6 +500,7 @@ def main() -> None:
                          "ordering is the point); drop --micro-batch")
     if args.sharded_spill_dir is not None and not args.sharded:
         raise SystemExit("--sharded-spill-dir needs --sharded")
+    print(f"[serve] compile cache: {use_compile_cache()}")
 
     graph, feats, psgs, fap, store, gen, infer_fn = build_stack(
         nodes=args.nodes, avg_degree=args.avg_degree, d_feat=args.d_feat,

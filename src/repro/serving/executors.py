@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.graph.sampler import (_sample_one_hop, device_sample,
                                  host_sample_dense)
 
@@ -394,7 +393,7 @@ class ShardedExecutor(BaseExecutor):
                 hops.append(frontier)
             return tuple(hops)
 
-        self._sample = jax.jit(shard_map(
+        self._sample = jax.jit(jax.shard_map(
             sample_body, mesh=mesh,
             in_specs=(P(), P(), P(axis), P()), out_specs=P(axis)))
 

@@ -344,7 +344,7 @@ def test_three_executor_engine_with_sharded_mesh():
     code = """
 import time
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core import (TieredFeatureStore, TopologySpec, compute_fap,
                         compute_psgs, quiver_placement)
 from repro.core.feature_store import ShardedFeatureStore
@@ -362,7 +362,7 @@ feats = np.random.default_rng(0).normal(size=(n, d)).astype(np.float32)
 topo = TopologySpec(num_pods=2, devices_per_pod=4, rows_per_device=128,
                     rows_host=256, hot_replicate_fraction=0.25)
 store = TieredFeatureStore.build(feats, quiver_placement(fap, topo))
-mesh = make_mesh((8,), ("x",))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 sstore = ShardedFeatureStore.from_tiered(store, mesh, "x")
 params = sage_init(jax.random.key(0), [d, 32, 32])
 

@@ -74,8 +74,8 @@ topo = TopologySpec(num_pods=2, devices_per_pod=4, rows_per_device=128,
                     rows_host=256, hot_replicate_fraction=0.25)
 plan = quiver_placement(fap, topo)
 store = TieredFeatureStore.build(feats, plan)
-from repro.compat import make_mesh
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 ss = ShardedFeatureStore.from_tiered(store, mesh, "x")
 ids = np.random.default_rng(2).integers(0, n, size=8 * 32).astype(np.int32)
 tt = plan.tier[ids]
@@ -109,8 +109,8 @@ topo = TopologySpec(num_pods=2, devices_per_pod=4, rows_per_device=128,
                     rows_host=256, hot_replicate_fraction=0.25)
 plan = quiver_placement(fap, topo)
 store = TieredFeatureStore.build(feats, plan)
-from repro.compat import make_mesh
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 ss = ShardedFeatureStore.from_tiered(store, mesh, "x")
 ids = np.random.default_rng(2).integers(0, n, size=8 * 32).astype(np.int32)
 ids[5] = -1                                  # padding stays zero

@@ -258,7 +258,7 @@ def test_sharded_lookup_hops_matches_per_hop():
     from conftest import run_subprocess
     code = """
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core import (ShardedFeatureStore, TieredFeatureStore,
                         TopologySpec, compute_fap, quiver_placement)
 from repro.graph import power_law_graph
@@ -269,7 +269,7 @@ topo = TopologySpec(num_pods=2, devices_per_pod=4, rows_per_device=64,
                     rows_host=128, hot_replicate_fraction=0.2)
 store = TieredFeatureStore.build(feats, quiver_placement(
     compute_fap(g, fan), topo))
-mesh = make_mesh((8,), ("x",))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 sstore = ShardedFeatureStore.from_tiered(store, mesh, "x")
 rng = np.random.default_rng(3)
 hops = [jnp.asarray(rng.integers(-1, n, size=s).astype(np.int32))

@@ -36,12 +36,12 @@ Pn, N, F = 8, 64, 5
 x = np.arange(N*F, dtype=np.float32).reshape(N, F)
 rng = np.random.default_rng(0)
 want = rng.integers(-1, N, size=(Pn, 16)).astype(np.int32)
-from repro.compat import make_mesh, shard_map
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 def body(x_local, want_local):
     return halo_gather(x_local, want_local[0], axis="x", num_shards=Pn,
                        rows_per_shard=N // Pn, cap_pp=16)[None]
-f = jax.jit(shard_map(body, mesh=mesh,
+f = jax.jit(jax.shard_map(body, mesh=mesh,
                           in_specs=(P("x", None), P("x", None)),
                           out_specs=P("x", None)))
 out = np.asarray(f(jnp.asarray(x), jnp.asarray(want)))
@@ -79,13 +79,13 @@ batch = {
 info = dict(nodes=n, edges=e, d_feat=d, classes=classes, graphs=None)
 params = _init(jax.random.key(0), d, classes, "ogb_products")
 ref = float(_loss(params, batch, info, "ogb_products"))
-from repro.compat import make_mesh, shard_map
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 ctx = HaloCtx(("x",), dict(mesh.shape), rows, cap_pp=e // shards)
 pspec = jax.tree_util.tree_map(lambda _: P(), params)
 bspec = {k: P("x", None) if v.ndim == 2 else P("x")
          for k, v in batch.items()}
-f = jax.jit(shard_map(
+f = jax.jit(jax.shard_map(
     lambda p, b: _loss_sharded(p, b, info, "ogb_products", ctx),
     mesh=mesh, in_specs=(pspec, bspec), out_specs=P()))
 out = float(f(params, batch))
@@ -122,13 +122,13 @@ info = dict(nodes=n, edges=e, d_feat=d, classes=classes, graphs=None)
 params = _reduced_init(jax.random.key(0), d, classes, "x")
 EDGE_CHUNKS["unit"] = 1
 ref = float(_loss(params, batch, info, "unit"))
-from repro.compat import make_mesh, shard_map
-mesh = make_mesh((8,), ("x",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 ctx = HaloCtx(("x",), dict(mesh.shape), rows, cap_pp=e // shards)
 pspec = jax.tree_util.tree_map(lambda _: P(), params)
 bspec = {k: P("x", None) if v.ndim == 2 else P("x")
          for k, v in batch.items()}
-f = jax.jit(shard_map(
+f = jax.jit(jax.shard_map(
     lambda p, b: _loss_sharded(p, b, info, "unit", ctx),
     mesh=mesh, in_specs=(pspec, bspec), out_specs=P()))
 out = float(f(params, batch))

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
 from repro.core import (Prefetcher, ShardedFeatureStore, TieredFeatureStore,
                         TopologySpec, compute_fap, quiver_placement)
 from repro.core.placement import TIER_HOST
@@ -24,7 +24,7 @@ from repro.core.fap import compute_fap
 from repro.core.placement import TopologySpec, quiver_placement
 from repro.core.feature_store import TieredFeatureStore, ShardedFeatureStore
 from repro.core.prefetch import Prefetcher
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 n, d = 2400, 16
 g = power_law_graph(n, 8.0, seed=0)
 fap = compute_fap(g, (4, 3))
@@ -33,7 +33,7 @@ topo = TopologySpec(num_pods=2, devices_per_pod=4, rows_per_device=96,
                     rows_host=300, hot_replicate_fraction=0.25)
 plan = quiver_placement(fap, topo)
 store = TieredFeatureStore.build(feats, plan)
-mesh = make_mesh((8,), ("x",))
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 """
 
 
@@ -182,7 +182,7 @@ def world1_stack(tmp_path_factory):
     topo = TopologySpec(num_pods=1, devices_per_pod=1, rows_per_device=64,
                         rows_host=150, hot_replicate_fraction=0.25)
     store = TieredFeatureStore.build(feats, quiver_placement(fap, topo))
-    mesh = make_mesh((1,), ("x",))
+    mesh = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,))
     spill_dir = str(tmp_path_factory.mktemp("shard_spill"))
     ss = ShardedFeatureStore.from_tiered(store, mesh, "x",
                                          spill_dir=spill_dir)

@@ -167,7 +167,6 @@ class Config:
     # -- trace-safety -----------------------------------------------------
     trace_wrappers: frozenset = frozenset({
         "jax.jit", "jit", "shard_map", "jax.shard_map",
-        "jax.experimental.shard_map.shard_map",
         "pl.pallas_call", "pallas_call", "jax.pmap", "pmap",
     })
     np_aliases: frozenset = frozenset({"np", "numpy", "onp"})
