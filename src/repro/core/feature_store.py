@@ -56,6 +56,7 @@ import numpy as np
 from jax.experimental import io_callback
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.core.placement import (PlacementPlan, TIER_DISK, TIER_HOST,
                                   TIER_HOT, TIER_WARM)
 from repro.graph.sampler import fixed_size_unique
@@ -70,8 +71,9 @@ from repro.kernels.tiered_gather.ops import tiered_gather
 # schema-sync pass cross-checks every producer and doc against it.
 STATS_SCHEMA: tuple = (
     "lookup_calls", "fused_calls", "fused_aggregates", "device_gathers",
-    "host_fetches", "disk_misses", "spill_reads", "prefetch_hits",
-    "prefetch_misses", "cache_hits", "cache_misses", "cache_evictions")
+    "host_fetches", "host_reads", "disk_misses", "spill_reads",
+    "prefetch_hits", "prefetch_misses", "cache_hits", "cache_misses",
+    "cache_evictions")
 
 
 def _new_stats() -> dict[str, int]:
@@ -92,6 +94,10 @@ def _new_stats() -> dict[str, int]:
                                    actually issued (a lookup whose cold rows
                                    are all staged — or that has none —
                                    issues zero)
+      host_reads                   blocking device→host reads a lookup
+                                   issued (``_read``: the cold-path checks,
+                                   the cache probe, ``lookup_aggregate``'s
+                                   segment build)
       disk_misses                  DISK-tier rows resolved synchronously on
                                    the lookup critical path
       spill_reads                  rows read from the DISK spill tier by any
@@ -295,6 +301,11 @@ class TieredFeatureStore:
     # (GPUFeatureCache): queried before tier dispatch, admitted on return.
     cache: Optional[object] = dataclasses.field(default=None, repr=False,
                                                 compare=False)
+    # Per-thread count of the device→host reads of the lookup running on
+    # that thread; each lookup entry adds it to ``host_reads`` in its one
+    # ``_count`` call, so counting reads takes no lock of its own.
+    _reads: threading.local = dataclasses.field(
+        default_factory=threading.local, repr=False, compare=False)
 
     @staticmethod
     def build(features: np.ndarray, plan: PlacementPlan, *,
@@ -376,6 +387,14 @@ class TieredFeatureStore:
             for k, v in deltas.items():
                 self.stats[k] += v
 
+    def _read(self, x) -> np.ndarray:
+        """Blocking device→host copy of ``x``: every such read on the
+        lookup path goes through here, so it is counted (``host_reads``)
+        and, when tracing, timed as ``store.read``."""
+        self._reads.n += 1
+        with tracing.span("store.read"):
+            return np.asarray(x)
+
     def reset_stats(self) -> dict[str, int]:
         """Zero the dispatch counters, returning the previous values."""
         with self._stats_lock:
@@ -420,16 +439,16 @@ class TieredFeatureStore:
             :meth:`swap_assignments`).
         """
         snap = self._snapshot()
-        self._count(lookup_calls=1)
+        self._reads.n = 0
         if dedup:
             uniq, inv = fixed_size_unique(jnp.asarray(ids, jnp.int32),
                                           int(ids.shape[0]))
             rows = self._cached_unique(uniq, include_host, snap, None,
-                                       fused=False)
-            out = rows[inv]
-            return jnp.where((jnp.asarray(ids) >= 0)[:, None], out, 0.0)
-        rows = self._cached_unique(jnp.asarray(ids, jnp.int32), include_host,
-                                   snap, None, fused=False)
+                                       fused=False)[inv]
+        else:
+            rows = self._cached_unique(jnp.asarray(ids, jnp.int32),
+                                       include_host, snap, None, fused=False)
+        self._count(lookup_calls=1, host_reads=self._reads.n)
         return jnp.where((jnp.asarray(ids) >= 0)[:, None], rows, 0.0)
 
     def lookup_hops(self, hops, *, include_host: bool = True,
@@ -463,21 +482,26 @@ class TieredFeatureStore:
         Raises:
             ValueError: if ``hops`` is empty or all hops have zero length.
         """
-        hops_j = [jnp.asarray(h, jnp.int32).reshape(-1) for h in hops]
-        sizes = [int(h.shape[0]) for h in hops_j]
-        total = sum(sizes)
-        if total == 0:
-            raise ValueError("lookup_hops needs at least one non-empty hop")
-        snap = self._snapshot()
-        self._count(fused_calls=1)
-        ids = hops_j[0] if len(hops_j) == 1 else jnp.concatenate(hops_j)
-        uniq, inv = fixed_size_unique(ids, total)
-        rows = self._cached_unique(uniq, include_host, snap, use_pallas,
-                                   fused=True)
-        out = jnp.where((ids >= 0)[:, None], rows[inv], 0.0)
-        offs = np.concatenate([[0], np.cumsum(sizes)])
-        return [out[int(offs[k]):int(offs[k + 1])]
-                for k in range(len(sizes))]
+        with tracing.span("store.lookup_hops"):
+            hops_j = [jnp.asarray(h, jnp.int32).reshape(-1) for h in hops]
+            sizes = [int(h.shape[0]) for h in hops_j]
+            total = sum(sizes)
+            if total == 0:
+                raise ValueError(
+                    "lookup_hops needs at least one non-empty hop")
+            snap = self._snapshot()
+            self._reads.n = 0
+            with tracing.span("store.dedup"):
+                ids = (hops_j[0] if len(hops_j) == 1
+                       else jnp.concatenate(hops_j))
+                uniq, inv = fixed_size_unique(ids, total)
+            rows = self._cached_unique(uniq, include_host, snap, use_pallas,
+                                       fused=True)
+            self._count(fused_calls=1, host_reads=self._reads.n)
+            out = jnp.where((ids >= 0)[:, None], rows[inv], 0.0)
+            offs = np.concatenate([[0], np.cumsum(sizes)])
+            return [out[int(offs[k]):int(offs[k + 1])]
+                    for k in range(len(sizes))]
 
     def lookup_aggregate(self, hops, *, include_host: bool = True,
                          use_pallas: Optional[bool] = None,
@@ -546,15 +570,15 @@ class TieredFeatureStore:
         fan = n_inner // p
         total = sum(sizes)
         snap = self._snapshot()
-        self._count(fused_calls=1, fused_aggregates=1)
+        self._reads.n = 0
         hot, warm = snap[0], snap[1]
         tier_t, slot_t = snap[4], snap[5]
         ids = jnp.concatenate(hops_j)
         uniq, inv = fixed_size_unique(ids, total)
-        uniq_np = np.asarray(uniq)
+        uniq_np = self._read(uniq)
         valid_u = uniq_np >= 0
-        tier_np = np.asarray(tier_t)[np.maximum(uniq_np, 0)]
-        slot_np = np.asarray(slot_t)[np.maximum(uniq_np, 0)]
+        tier_np = self._read(tier_t)[np.maximum(uniq_np, 0)]
+        slot_np = self._read(slot_t)[np.maximum(uniq_np, 0)]
         cold = valid_u & (tier_np >= TIER_HOST)
         cold_idx = np.flatnonzero(cold)
         # per-unique kernel addresses: 0=hot, 1=warm, 2=cold table, 99=skip
@@ -579,8 +603,8 @@ class TieredFeatureStore:
             # device-only probe (or nothing cold): cold children contribute
             # zero rows, exactly like the unfused include_host=False path
             cold_buf = jnp.zeros((1, hot.shape[1]), hot.dtype)
-        inner_np = np.asarray(hops_j[-1])
-        inv_np = np.asarray(inv)
+        inner_np = self._read(hops_j[-1])
+        inv_np = self._read(inv)
         inv_inner = inv_np[total - n_inner:]
         # segment matrix: one singleton segment per unique id (recovers the
         # outer-hop feature rows from the same dispatch), then one fan-wide
@@ -594,7 +618,8 @@ class TieredFeatureStore:
                                     ktier[inv_inner]).reshape(p, fan)
         seg_slot[total:] = np.where(inner_np < 0, 0,
                                     kslot[inv_inner]).reshape(p, fan)
-        self._count(device_gathers=1)
+        self._count(fused_calls=1, fused_aggregates=1, device_gathers=1,
+                    host_reads=self._reads.n)
         out = gather_aggregate(jnp.asarray(seg_tier), jnp.asarray(seg_slot),
                                hot, warm, cold_buf, block_rows=block_rows,
                                block_dim=block_dim, use_pallas=use_pallas
@@ -644,8 +669,8 @@ class TieredFeatureStore:
         if cache is None or not include_host:
             self._count(device_gathers=gathers)
             return tier_path(uniq, include_host, snap)
-        uniq_np = np.asarray(uniq)
-        tier_np = np.asarray(snap[4][jnp.maximum(jnp.asarray(uniq), 0)])
+        uniq_np = self._read(uniq)
+        tier_np = self._read(snap[4][jnp.maximum(jnp.asarray(uniq), 0)])
         cold = (uniq_np >= 0) & (tier_np >= TIER_HOST)
         if not cold.any():
             self._count(device_gathers=gathers)
@@ -676,19 +701,22 @@ class TieredFeatureStore:
         optimization — and HOST/DISK rows come from the staging buffer
         (prefetch hit) or one ``_host_fetch`` (miss fallback)."""
         hot, warm, host, disk, tier_t, slot_t, stage = snap
-        safe = jnp.maximum(uniq, 0)
-        tier = tier_t[safe]
-        slot = slot_t[safe]
-        # address-sort key: tier-major, slot-minor. Slots are clamped into
-        # the device-tier span only for key construction (host-tier slots
-        # may exceed it; their gather result is zeros either way), which
-        # keeps the key within int32 for any store below ~5e8 rows/tier.
-        span = jnp.int32(max(int(hot.shape[0]), int(warm.shape[0]), 1))
-        key = tier.astype(jnp.int32) * span + jnp.minimum(slot, span - 1)
-        order = jnp.argsort(key)
-        dev_sorted = tiered_gather(tier[order], slot[order], hot, warm,
-                                   use_pallas=use_pallas)[:, :self.feat_dim]
-        out = jnp.zeros_like(dev_sorted).at[order].set(dev_sorted)
+        with tracing.span("store.gather"):
+            safe = jnp.maximum(uniq, 0)
+            tier = tier_t[safe]
+            slot = slot_t[safe]
+            # address-sort key: tier-major, slot-minor. Slots are clamped
+            # into the device-tier span only for key construction (host-tier
+            # slots may exceed it; their gather result is zeros either way),
+            # which keeps the key within int32 for any store below ~5e8
+            # rows/tier.
+            span = jnp.int32(max(int(hot.shape[0]), int(warm.shape[0]), 1))
+            key = tier.astype(jnp.int32) * span + jnp.minimum(slot, span - 1)
+            order = jnp.argsort(key)
+            dev_sorted = tiered_gather(
+                tier[order], slot[order], hot, warm,
+                use_pallas=use_pallas)[:, :self.feat_dim]
+            out = jnp.zeros_like(dev_sorted).at[order].set(dev_sorted)
         if include_host:
             out = self._resolve_cold(uniq, tier, slot, out, host, disk,
                                      stage)
@@ -744,42 +772,43 @@ class TieredFeatureStore:
         bit-identical to the host/disk rows (they are copies of the same
         float values), so this path never changes lookup results.
         """
-        ids_np = np.asarray(ids)
-        tier_np = np.asarray(tier)
-        cold = (tier_np >= TIER_HOST) & (ids_np >= 0)
-        if not cold.any():
+        with tracing.span("store.cold"):
+            ids_np = self._read(ids)
+            tier_np = self._read(tier)
+            cold = (tier_np >= TIER_HOST) & (ids_np >= 0)
+            if not cold.any():
+                return out
+            miss = cold
+            if stage is not None:
+                stage_slot, stage_rows = stage
+                sslot = stage_slot[np.maximum(ids_np, 0)]
+                hit = cold & (sslot >= 0)
+                miss = cold & ~hit
+                self._count(prefetch_hits=int(hit.sum()),
+                            prefetch_misses=int(miss.sum()))
+                if hit.any():
+                    # full-width gather + where keeps the shapes static (one
+                    # compile per id-bucket, like the host path) — a dynamic
+                    # hit-index scatter would recompile on every hit count
+                    gathered = stage_rows[jnp.asarray(np.maximum(sslot, 0))]
+                    out = jnp.where(jnp.asarray(hit)[:, None], gathered, out)
+            if miss.any():
+                disk_miss = miss & (tier_np == TIER_DISK)
+                n_disk = int(disk_miss.sum())
+                self._count(host_fetches=1, disk_misses=n_disk,
+                            spill_reads=n_disk)
+                if n_disk:
+                    with self._stats_lock:
+                        if self._disk_miss_counts is not None:
+                            np.add.at(self._disk_miss_counts,
+                                      ids_np[disk_miss], 1)
+                # mask the staged positions out of the callback's tier vector
+                # so it only gathers the rows that actually missed
+                tier_eff = jnp.asarray(np.where(miss, tier_np, -1)
+                                       .astype(np.int32))
+                rows = self._host_fetch(ids, tier_eff, slot, host, disk)
+                out = jnp.where(jnp.asarray(miss)[:, None], rows, out)
             return out
-        miss = cold
-        if stage is not None:
-            stage_slot, stage_rows = stage
-            sslot = stage_slot[np.maximum(ids_np, 0)]
-            hit = cold & (sslot >= 0)
-            miss = cold & ~hit
-            self._count(prefetch_hits=int(hit.sum()),
-                        prefetch_misses=int(miss.sum()))
-            if hit.any():
-                # full-width gather + where keeps the shapes static (one
-                # compile per id-bucket, like the host path) — a dynamic
-                # hit-index scatter would recompile on every hit count
-                gathered = stage_rows[jnp.asarray(np.maximum(sslot, 0))]
-                out = jnp.where(jnp.asarray(hit)[:, None], gathered, out)
-        if miss.any():
-            disk_miss = miss & (tier_np == TIER_DISK)
-            n_disk = int(disk_miss.sum())
-            self._count(host_fetches=1, disk_misses=n_disk,
-                        spill_reads=n_disk)
-            if n_disk:
-                with self._stats_lock:
-                    if self._disk_miss_counts is not None:
-                        np.add.at(self._disk_miss_counts, ids_np[disk_miss],
-                                  1)
-            # mask the staged positions out of the callback's tier vector so
-            # it only gathers the rows that actually missed
-            tier_eff = jnp.asarray(np.where(miss, tier_np, -1)
-                                   .astype(np.int32))
-            rows = self._host_fetch(ids, tier_eff, slot, host, disk)
-            out = jnp.where(jnp.asarray(miss)[:, None], rows, out)
-        return out
 
     def _host_fetch(self, ids, tier, slot, host=None, disk=None):
         """PCIe-analogue slow path: host callback, ids sorted by address
@@ -789,25 +818,32 @@ class TieredFeatureStore:
             # could tear across a concurrent migration publish
             _, _, host, disk, _, _, _ = self._snapshot()
 
-        def cb(tier_np, slot_np):
-            tier_np = np.asarray(tier_np)
-            slot_np = np.asarray(slot_np)
-            out = np.zeros((tier_np.shape[0], host.shape[1]), host.dtype)
-            m_h = tier_np == TIER_HOST
-            m_d = tier_np == TIER_DISK
-            # address-sorted gathers
-            for m, store in ((m_h, host), (m_d, disk)):
-                idx = np.flatnonzero(m)
-                if idx.size:
-                    order = np.argsort(slot_np[idx])
-                    rows = store[slot_np[idx][order]]
-                    out[idx[order]] = rows
-            return out
+        with tracing.span("store.host_fetch"):
+            # the callback body runs on a runtime thread: it is handed the
+            # request and parent span of this dispatch explicitly
+            ctx = tracing.current()
 
-        return io_callback(
-            cb, jax.ShapeDtypeStruct((ids.shape[0], self.feat_dim),
-                                     host.dtype), tier, slot,
-            ordered=False)
+            def cb(tier_np, slot_np):
+                with tracing.span("store.callback", ctx=ctx):
+                    tier_np = np.asarray(tier_np)
+                    slot_np = np.asarray(slot_np)
+                    out = np.zeros((tier_np.shape[0], host.shape[1]),
+                                   host.dtype)
+                    m_h = tier_np == TIER_HOST
+                    m_d = tier_np == TIER_DISK
+                    # address-sorted gathers
+                    for m, store in ((m_h, host), (m_d, disk)):
+                        idx = np.flatnonzero(m)
+                        if idx.size:
+                            order = np.argsort(slot_np[idx])
+                            rows = store[slot_np[idx][order]]
+                            out[idx[order]] = rows
+                    return out
+
+            return io_callback(
+                cb, jax.ShapeDtypeStruct((ids.shape[0], self.feat_dim),
+                                         host.dtype), tier, slot,
+                ordered=False)
 
     # -- prefetch staging ----------------------------------------------------
     def publish_stage(self, stage_slot: Optional[np.ndarray],

@@ -36,6 +36,7 @@ from typing import Any, Callable, Iterable, Mapping, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.serving.executors import Executor
 from repro.serving.registry import DEFAULT_MODEL, ModelEntry, ModelRegistry
 
@@ -471,9 +472,19 @@ class ServingEngine:
         """
         if not batch:
             raise ValueError("submit_batch needs a non-empty batch")
+        if not tracing.enabled():
+            return self._submit_batch(batch)
+        with tracing.span("engine.submit",
+                          req=getattr(batch[0], "req_id", None)):
+            return self._submit_batch(batch)
+
+    def _submit_batch(self, batch: list) -> Optional[Future]:
         model = _batch_model(batch)
         entry = self.registry.get(model)
-        if not self._window.acquire(blocking=self.admission == "wait"):
+        with tracing.span("engine.admit"):
+            admitted = self._window.acquire(
+                blocking=self.admission == "wait")
+        if not admitted:
             self.record_shed(batch, model)
             return None
         with self._lock:         # bind this run: stragglers from a failed
@@ -485,7 +496,8 @@ class ServingEngine:
             # route only admitted batches, so router.routed matches executed
             # work and load-aware estimates see post-admission inflight
             seeds = _batch_seeds(batch)
-            name = entry.router.route(seeds)
+            with tracing.span("router.route"):
+                name = entry.router.route(seeds)
             submitted_at = self.clock()
             fut = entry.executors[name].submit(seeds)
         except BaseException:

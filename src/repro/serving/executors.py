@@ -21,7 +21,8 @@ pipelines in a processor", §4.3(1)) and exposes
   ``capacity``      number of batches it can process concurrently.
 
 This module must stay importable without ``repro.core`` (the core package
-shims onto it), so it depends only on ``repro.graph`` + numpy/jax.
+shims onto it), so it depends only on ``repro.graph``, ``repro.tracing`` and
+numpy/jax.
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.graph.sampler import (_sample_one_hop, device_sample,
                                  host_sample_dense)
 
@@ -217,9 +219,12 @@ class BaseExecutor:
                             getattr(self, "sstore", None)) if s is not None]
 
     def run(self, seeds: np.ndarray) -> jnp.ndarray:
-        """Synchronous convenience path (calibration, warmup, debugging)."""
-        out = self.process(np.asarray(seeds))
-        jax.block_until_ready(out)
+        """Process one batch and wait for its output: the body of a worker
+        lane, and the synchronous path of calibration and warm-up."""
+        with tracing.span("executor.run", kind=self.kind):
+            out = self.process(np.asarray(seeds))
+            with tracing.span("executor.sync"):
+                jax.block_until_ready(out)
         return out
 
     def submit(self, seeds: np.ndarray) -> Future:
@@ -227,7 +232,7 @@ class BaseExecutor:
         resolves to the ``(B, d_out)`` output of :meth:`process`."""
         with self._lock:
             self._inflight += 1
-        fut = self._pool.submit(self.run, seeds)
+        fut = self._pool.submit(tracing.in_lane(self.run), seeds)
         fut.add_done_callback(self._one_done)
         return fut
 
@@ -270,13 +275,16 @@ class HostExecutor(BaseExecutor):
         returns one output row per seed."""
         n = int(seeds.shape[0])
         seeds_p = pad_to_bucket(np.asarray(seeds).astype(np.int32))
-        hops_np = host_sample_dense(self._child_rng(), self.graph, seeds_p,
-                                    self.fanouts)
-        hops = [jnp.asarray(h) for h in hops_np]
-        hop_feats, deep_agg = self._collect(self.store, hops)
-        if deep_agg is not None:
-            return self.infer_fn(hop_feats, hops, deep_agg=deep_agg)[:n]
-        return self.infer_fn(hop_feats, hops)[:n]
+        with tracing.span("executor.sample"):
+            hops_np = host_sample_dense(self._child_rng(), self.graph,
+                                        seeds_p, self.fanouts)
+            hops = [jnp.asarray(h) for h in hops_np]
+        with tracing.span("executor.collect"):
+            hop_feats, deep_agg = self._collect(self.store, hops)
+        with tracing.span("executor.infer"):
+            if deep_agg is not None:
+                return self.infer_fn(hop_feats, hops, deep_agg=deep_agg)[:n]
+            return self.infer_fn(hop_feats, hops)[:n]
 
 
 class DeviceExecutor(BaseExecutor):
@@ -312,12 +320,15 @@ class DeviceExecutor(BaseExecutor):
             chunk = seeds[lo:lo + self.max_batch]
             seeds_p = np.full((self.max_batch,), -1, np.int32)
             seeds_p[:chunk.shape[0]] = chunk
-            hops = device_sample(self._next_key(), *self.graph_dev,
-                                 jnp.asarray(seeds_p), self.fanouts)
-            hop_feats, deep_agg = self._collect(self.store, hops)
-            out = (self.infer_fn(hop_feats, hops, deep_agg=deep_agg)
-                   if deep_agg is not None
-                   else self.infer_fn(hop_feats, hops))
+            with tracing.span("executor.sample"):
+                hops = device_sample(self._next_key(), *self.graph_dev,
+                                     jnp.asarray(seeds_p), self.fanouts)
+            with tracing.span("executor.collect"):
+                hop_feats, deep_agg = self._collect(self.store, hops)
+            with tracing.span("executor.infer"):
+                out = (self.infer_fn(hop_feats, hops, deep_agg=deep_agg)
+                       if deep_agg is not None
+                       else self.infer_fn(hop_feats, hops))
             outs.append(out[:chunk.shape[0]])
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
@@ -435,13 +446,17 @@ class ShardedExecutor(BaseExecutor):
             chunk = seeds[lo:lo + self.max_batch]
             seeds_p = np.full((self.max_batch,), -1, np.int32)
             seeds_p[:chunk.shape[0]] = chunk
-            hops = list(self._sample(*self.graph_dev, jnp.asarray(seeds_p),
-                                     self._next_key()))
+            with tracing.span("executor.sample"):
+                hops = list(self._sample(*self.graph_dev,
+                                         jnp.asarray(seeds_p),
+                                         self._next_key()))
             # ShardedFeatureStore has no lookup_aggregate — _collect falls
             # back to the fused whole-row path there, deep_agg stays None
-            hop_feats, deep_agg = self._collect(self.sstore, hops)
-            out = (self.infer_fn(hop_feats, hops, deep_agg=deep_agg)
-                   if deep_agg is not None
-                   else self.infer_fn(hop_feats, hops))
+            with tracing.span("executor.collect"):
+                hop_feats, deep_agg = self._collect(self.sstore, hops)
+            with tracing.span("executor.infer"):
+                out = (self.infer_fn(hop_feats, hops, deep_agg=deep_agg)
+                       if deep_agg is not None
+                       else self.infer_fn(hop_feats, hops))
             outs.append(out[:chunk.shape[0]])
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
