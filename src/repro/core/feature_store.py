@@ -45,6 +45,7 @@ row count (hop frontiers overlap heavily on skewed graphs).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import threading
 from functools import partial
@@ -73,7 +74,7 @@ STATS_SCHEMA: tuple = (
     "lookup_calls", "fused_calls", "fused_aggregates", "device_gathers",
     "host_fetches", "host_reads", "disk_misses", "spill_reads",
     "prefetch_hits", "prefetch_misses", "cache_hits", "cache_misses",
-    "cache_evictions")
+    "cache_evictions", "host_fetch_traces")
 
 
 def _new_stats() -> dict[str, int]:
@@ -114,6 +115,9 @@ def _new_stats() -> dict[str, int]:
                                    admitted on return)
       cache_evictions              resident cache rows displaced by those
                                    admissions
+      host_fetch_traces            traces of the jitted host-fetch program
+                                   (one per placement snapshot and id-vector
+                                   length; each is followed by a compile)
     """
     return dict.fromkeys(STATS_SCHEMA, 0)
 
@@ -306,6 +310,17 @@ class TieredFeatureStore:
     # ``_count`` call, so counting reads takes no lock of its own.
     _reads: threading.local = dataclasses.field(
         default_factory=threading.local, repr=False, compare=False)
+    # (host, disk, jitted fetch) of the snapshot ``_host_fetch`` last built
+    # its program for; replaced whole, so a reader needs no lock
+    _fetch_prog: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    # Tracing contexts of the host fetches in flight, by the int32 token
+    # each hands its program (token 0: tracing off, nothing kept); the
+    # callback pops its own entry.
+    _fetch_ctx: dict = dataclasses.field(default_factory=dict, repr=False,
+                                         compare=False)
+    _fetch_tokens: itertools.count = dataclasses.field(
+        default_factory=itertools.count, repr=False, compare=False)
 
     @staticmethod
     def build(features: np.ndarray, plan: PlacementPlan, *,
@@ -806,44 +821,71 @@ class TieredFeatureStore:
                 # so it only gathers the rows that actually missed
                 tier_eff = jnp.asarray(np.where(miss, tier_np, -1)
                                        .astype(np.int32))
-                rows = self._host_fetch(ids, tier_eff, slot, host, disk)
+                rows = self._host_fetch(tier_eff, slot, host, disk)
                 out = jnp.where(jnp.asarray(miss)[:, None], rows, out)
             return out
 
-    def _host_fetch(self, ids, tier, slot, host=None, disk=None):
+    def _host_fetch(self, tier, slot, host=None, disk=None):
         """PCIe-analogue slow path: host callback, ids sorted by address
-        (the paper's TLB optimization) before the gather."""
+        (the paper's TLB optimization) before the gather.
+
+        The callback runs inside one jitted program per placement snapshot
+        (its ``host``/``disk`` objects, compared by identity), which JAX
+        compiles once per id-vector length; a fetch on a snapshot that is
+        not the cached one builds that snapshot's own program, so a lookup
+        never reads rows of another snapshot. The request's tracing context
+        rides to the callback as an int32 token operand, not a closure."""
         if host is None:
             # one coherent snapshot — reading the two attributes directly
             # could tear across a concurrent migration publish
             _, _, host, disk, _, _, _ = self._snapshot()
 
         with tracing.span("store.host_fetch"):
-            # the callback body runs on a runtime thread: it is handed the
-            # request and parent span of this dispatch explicitly
-            ctx = tracing.current()
+            prog, ctxs = self._fetch_prog, self._fetch_ctx
+            if prog is None or prog[0] is not host or prog[1] is not disk:
+                def cb(tier_np, slot_np, token):
+                    # the body runs on a runtime thread: the dispatching
+                    # request's context comes back through its token
+                    token = int(token)
+                    ctx = ctxs.pop(token, None) if token else None
+                    with tracing.span("store.callback", ctx=ctx):
+                        tier_np = np.asarray(tier_np)
+                        slot_np = np.asarray(slot_np)
+                        out = np.zeros((tier_np.shape[0], host.shape[1]),
+                                       host.dtype)
+                        m_h = tier_np == TIER_HOST
+                        m_d = tier_np == TIER_DISK
+                        # address-sorted gathers
+                        for m, store in ((m_h, host), (m_d, disk)):
+                            idx = np.flatnonzero(m)
+                            if idx.size:
+                                order = np.argsort(slot_np[idx])
+                                rows = store[slot_np[idx][order]]
+                                out[idx[order]] = rows
+                        return out
 
-            def cb(tier_np, slot_np):
-                with tracing.span("store.callback", ctx=ctx):
-                    tier_np = np.asarray(tier_np)
-                    slot_np = np.asarray(slot_np)
-                    out = np.zeros((tier_np.shape[0], host.shape[1]),
-                                   host.dtype)
-                    m_h = tier_np == TIER_HOST
-                    m_d = tier_np == TIER_DISK
-                    # address-sorted gathers
-                    for m, store in ((m_h, host), (m_d, disk)):
-                        idx = np.flatnonzero(m)
-                        if idx.size:
-                            order = np.argsort(slot_np[idx])
-                            rows = store[slot_np[idx][order]]
-                            out[idx[order]] = rows
-                    return out
+                fetch = jax.jit(lambda tier, slot, token: io_callback(
+                    cb, self._fetch_type(tier, host.dtype), tier, slot,
+                    token, ordered=False))
+                # two racing builds are both this snapshot's; either is kept
+                prog = (host, disk, fetch)
+                self._fetch_prog = prog
+            token = 0
+            if tracing.enabled():
+                token = next(self._fetch_tokens) % (2**31 - 1) + 1
+                ctxs[token] = tracing.current()
+            try:
+                return prog[2](tier, slot, np.int32(token))
+            except BaseException:
+                ctxs.pop(token, None)
+                raise
 
-            return io_callback(
-                cb, jax.ShapeDtypeStruct((ids.shape[0], self.feat_dim),
-                                         host.dtype), tier, slot,
-                ordered=False)
+    def _fetch_type(self, tier, dtype) -> jax.ShapeDtypeStruct:
+        """Result type of the host-fetch program for ``tier``'s length.
+        Python calls this only while JAX traces the program (once per
+        snapshot and id-vector length), so it counts the traces."""
+        self._count(host_fetch_traces=1)
+        return jax.ShapeDtypeStruct((tier.shape[0], self.feat_dim), dtype)
 
     # -- prefetch staging ----------------------------------------------------
     def publish_stage(self, stage_slot: Optional[np.ndarray],

@@ -795,12 +795,11 @@ class TestLockRegressions:
             feats, quiver_placement(compute_fap(g, (4, 3)), topo))
         hot, warm, host, disk, tier_t, slot_t, _ = store._snapshot()
         cold = np.flatnonzero(np.asarray(tier_t) >= 2)[:16]
-        ids = jnp.asarray(cold, jnp.int32)
         tier = jnp.asarray(np.asarray(tier_t)[cold].astype(np.int32))
         slot = jnp.asarray(np.asarray(slot_t)[cold].astype(np.int32))
-        via_default = np.asarray(store._host_fetch(ids, tier, slot))
+        via_default = np.asarray(store._host_fetch(tier, slot))
         via_explicit = np.asarray(
-            store._host_fetch(ids, tier, slot, host, disk))
+            store._host_fetch(tier, slot, host, disk))
         np.testing.assert_array_equal(via_default, via_explicit)
         np.testing.assert_allclose(via_default, feats[cold])
 
