@@ -31,22 +31,25 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 from bench import run as bench_run  # noqa: E402
 
 
-def readings(caps, params, data, fanouts) -> dict:
+def readings(caps, model, params, data, fanouts) -> dict:
     from bench.lib import harness, reference
 
     out = {}
     for prec in ("highest", "default"):
-        nums, per_exec = harness.check(caps, params, data, fanouts, {}, prec)
+        nums, per_exec = harness.check(caps, model, params, data, fanouts,
+                                       {}, prec)
         out[f"program_gap_{prec}"] = nums["output_gap"]
     out.update(sample_bad_slots=nums["sample_bad_slots"],
                row_mismatches=nums["row_mismatches"], checked=per_exec)
     ctrl = []
     for c in caps:
-        rows, o = reference.control_output(params, c["hops"], data[2],
-                                           fanouts, c["out"].shape[0])
+        rows, o = reference.control_output(model, params, c["hops"],
+                                           data[2], fanouts,
+                                           c["out"].shape[0])
         ctrl.append({**c, "rows": rows, "out": o})
     for prec in ("highest", "default"):
-        nums, _ = harness.check(ctrl, params, data, fanouts, {}, prec)
+        nums, _ = harness.check(ctrl, model, params, data, fanouts, {},
+                                prec)
         out[f"control_gap_{prec}"] = nums["output_gap"]
     out["control_row_mismatches"] = nums["row_mismatches"]
     return out
@@ -68,7 +71,7 @@ def main() -> int:
         cells.cache_dir(), "jax"))
     if bench_run.find_chips(jax, int(cell["chips"])) is None:
         return 3
-    from bench.lib import harness, model
+    from bench.lib import harness
     from bench.lib import traffic as tr
 
     rec = harness.Recorder(trace=False)
@@ -79,13 +82,13 @@ def main() -> int:
     data = (system.indptr, system.indices, system.feats)
     rows = []
     for seed in seeds:
-        system.model.params = model.make_params(seed, cfg["feat_dim"],
-                                                cfg["hidden"])
+        system.use_params(system.model_mod.make_params(seed, cfg))
         reqs, _ = harness.drive(system, mix, args.seconds, seed, draw, rec)
         caps = harness.host_captures(reqs)
         r = {"seed": seed, "requests": len(reqs),
              "failed": harness.failed(reqs),
-             **readings(caps, system.params, data, system.fanouts)}
+             **readings(caps, system.model_mod, system.params, data,
+                        system.fanouts)}
         rows.append(r)
         print(json.dumps(r), flush=True)
     keys = ("program_gap_highest", "program_gap_default")

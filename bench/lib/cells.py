@@ -1,23 +1,40 @@
-"""Find a cell's configuration, traffic mix and metric readers by name.
+"""Find a cell's configuration, traffic mix, served model and metric
+readers by name.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own under ``bench/``:
+Everything that belongs to one configuration, one traffic mix, one served
+model or one per-layer metric is a file of its own under ``bench/``:
 
-    bench/configs/<config>.json   sizes, placement, executor settings
+    bench/configs/<config>.json   sizes, placement, executor settings, and
+                                  ``model``: the name of the served model
     bench/traffic/<mix>.json      loop, clients, request sizes, popularity
     bench/cells/<cell>.json       what one cell sets of its mix: the
                                   offered rate, 4/5 of that cell's knee
+    bench/models/<model>.py       the served model (below)
     bench/metrics/<metric>.py     ``read(run) -> float | None``
 
 and ``BENCHMARK.json`` at the checkout root names the cells. Adding a cell
 adds files and a ``workloads`` entry; no file here changes, and a mix
 serves any configuration unedited.
+
+A served model is a config whose ``model`` names a new
+``bench/models/<model>.py`` (and metric readers of its own, where it has
+kernels of its own). The file gives four functions:
+
+    make_params(seed, cfg)      weights made on the device from ``--seed``
+    served(params, cfg)         the ``infer_fn(hop_feats, hop_ids,
+                                deep_agg=None)`` handed to the executors,
+                                built on the program's own model code
+    reference(params, hop_rows, hop_ids, fanouts, *, dtype, precision)
+                                plain ``jax.numpy``, importing nothing of
+                                the program: what ``correct`` compares with
+    flops_per_seed(cfg)         model FLOPs one seed requires (``step_mfu``)
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -84,3 +101,23 @@ def reader(metric: str, root: str | None = None):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+_MODELS: dict[str, object] = {}
+
+
+def model(name: str, root: str | None = None):
+    """The served model ``bench/models/<name>.py`` of the checkout at
+    ``root`` (a model that root does not hold is the harness's own),
+    executed once a process, so every caller gets the same module."""
+    path = os.path.join(root or ROOT, "bench", "models", f"{name}.py")
+    if not os.path.exists(path):
+        path = os.path.join(BENCH_DIR, "models", f"{name}.py")
+    if path not in _MODELS:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_model_{len(_MODELS)}", path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod
+        spec.loader.exec_module(mod)
+        _MODELS[path] = mod
+    return _MODELS[path]

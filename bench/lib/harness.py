@@ -4,7 +4,8 @@ and check what it served.
 The window drives the program's own pieces: ``compute_psgs``,
 ``compute_fap`` and ``quiver_placement`` → ``TieredFeatureStore.build`` →
 ``build_executors`` (host and device executors) → ``calibrate_executors``
-→ ``CostModelRouter`` → ``ServingEngine``. The load generator calls
+→ ``CostModelRouter`` → ``ServingEngine``. The served model is the
+configuration's ``model`` (``bench/models``). The load generator calls
 ``ServingEngine.submit_batch([request])`` for each request and times it
 from when it was due. The benchmark's own wrappers sit around the
 executors' ``submit``/``run``, the ``infer_fn`` and (traced runs only)
@@ -26,7 +27,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from bench.lib import counts, data, reference, traffic as tr
+from bench.lib import cells, counts, data, reference, traffic as tr
 
 GRACE_S = 60.0           # how long past the close a due answer is awaited
 LEAD_S = 0.05            # schedule starts this long after the last set-up step
@@ -67,8 +68,10 @@ class Recorder:
                 self.spans[name].append((start, end))
 
     def on_compile_event(self, event: str, *args, **kwargs) -> None:
-        if self.active and ("backend_compile" in event
-                            or event.endswith("cache_hits")):
+        """Counts each compilation once: JAX times every backend compile,
+        a read from the persistent compile cache included (which also
+        sends a ``cache_hits`` event of its own)."""
+        if self.active and event.endswith("backend_compile_duration"):
             with self._lock:
                 self.compiles += 1
 
@@ -200,7 +203,6 @@ class System:
                                 compute_fap, compute_psgs, quiver_placement)
         from repro.graph import CSRGraph
         from repro.launch.serve import build_executors
-        from bench.lib import model
 
         t = time.monotonic()
         self.cfg = cfg
@@ -225,10 +227,9 @@ class System:
         self.store = TieredFeatureStore.build(self.feats, plan)
         log(f"placement: {plan.tier_counts()} "
             f"({time.monotonic() - t:.1f} s)")
-        self.model = model.Served(
-            model.make_params(seed, cfg["feat_dim"], cfg["hidden"]),
-            self.fanouts)
-        infer = _wrap_infer(rec, self.model)
+        self.model_mod = cells.model(cfg["model"], root)
+        self.use_params(self.model_mod.make_params(seed, cfg))
+        infer = _wrap_infer(rec, self._infer)
         sv = cfg["serving"]
         self.executors = build_executors(
             graph, self.store, self.fanouts, infer, psgs,
@@ -241,6 +242,15 @@ class System:
         self.psgs = psgs
         self.router = self.engine = None
         self._t = t
+
+    def use_params(self, params) -> None:
+        """Serve ``params`` from the next request on (the compiled
+        programs take the weights as an argument)."""
+        self.params = params
+        self.model = self.model_mod.served(params, self.cfg)
+
+    def _infer(self, hop_feats, hop_ids, deep_agg=None):
+        return self.model(hop_feats, hop_ids, deep_agg)
 
     def calibrate(self, log=print) -> None:
         """The program's calibration, then its router and engine. Run it
@@ -267,10 +277,6 @@ class System:
             log(f"curve {name}: psgs {np.round(c.psgs, 1).tolist()} avg_ms "
                 f"{np.round(c.avg * 1e3, 2).tolist()} tail_ms "
                 f"{np.round(c.mx * 1e3, 2).tolist()}")
-
-    @property
-    def params(self) -> dict:
-        return self.model.params
 
     def close(self) -> None:
         """Stop the lanes and free the program's device state."""
@@ -414,6 +420,7 @@ class RunRecord:
     trace: Optional[dict]
     peaks: dict
     flops_per_seed: int
+    compiles: int = 0
 
     @property
     def completed(self) -> list:
@@ -474,10 +481,11 @@ def host_captures(reqs: list) -> list[dict]:
     return out
 
 
-def check(caps: list[dict], params, system_data: tuple, fanouts,
+def check(caps: list[dict], model, params, system_data: tuple, fanouts,
           limit: dict, precision: str) -> tuple[dict, dict]:
-    """Compare the captured requests with the plain reference. Returns
-    (numbers, per-executor counts)."""
+    """Compare the captured requests with the plain reference of the
+    served ``model`` (its module, ``cells.model``). Returns (numbers,
+    per-executor counts)."""
     indptr, indices, feats = system_data
     bad = rows = 0
     gap = 0.0
@@ -487,8 +495,8 @@ def check(caps: list[dict], params, system_data: tuple, fanouts,
         bad += reference.bad_sample_slots(c["seeds"], c["hops"], fanouts,
                                           indptr, indices)
         rows += reference.row_mismatches(c["rows"], c["hops"], feats)
-        gap = max(gap, reference.output_gap(params, c["out"], c["hops"],
-                                            feats, fanouts,
+        gap = max(gap, reference.output_gap(model, params, c["out"],
+                                            c["hops"], feats, fanouts,
                                             precision=precision))
     return ({"sample_bad_slots": bad, "row_mismatches": rows,
              "output_gap": gap}, dict(per_exec))

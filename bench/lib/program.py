@@ -77,16 +77,6 @@ def on_trace(records, start_ns: int) -> dict[str, list[tuple[int, int]]]:
     return dict(out)
 
 
-def _open_at(intervals, ts: np.ndarray) -> np.ndarray:
-    """For each time in ``ts``: is any interval [s, e) open there."""
-    if not intervals:
-        return np.zeros(ts.shape, bool)
-    iv = np.asarray(intervals, np.int64)
-    s, e = np.sort(iv[:, 0]), np.sort(iv[:, 1])
-    return (np.searchsorted(s, ts, side="right")
-            - np.searchsorted(e, ts, side="right")) > 0
-
-
 def idle_by_span(tr: dict, spans: dict) -> list[list]:
     """Idle seconds of the traced window by the program span open at each
     gap's midpoint (first of ``GAP_ORDER``), else ``no_request``; ``tr`` is
@@ -100,7 +90,7 @@ def idle_by_span(tr: dict, spans: dict) -> list[list]:
     left = np.ones(len(g), bool)
     out = []
     for name in GAP_ORDER:
-        hit = left & _open_at(spans.get(name, ()), mid)
+        hit = left & trace.open_at(spans.get(name, ()), mid)
         if hit.any():
             out.append([name, float(dur[hit].sum()) * 1e-9])
             left &= ~hit

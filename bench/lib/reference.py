@@ -7,53 +7,34 @@ Three layers are checked on requests captured inside the window:
   padding; a larger one yields neighbours only; padding yields padding);
 * feature collection: every collected row is bit-identical to the
   feature table's row, from whichever tier served it;
-* the model: the served output against a plain ``jax.numpy`` float32
-  GraphSAGE (mean aggregator, layer norm, ReLU between layers) on the
-  same sampled subgraph and the table's rows.
+* the model: the served output against the served model's own plain
+  ``jax.numpy`` ``reference`` (``bench/models/<model>.py``) on the same
+  sampled subgraph and the table's rows.
 
-Nothing here imports the program. ``sage_reference`` follows the
-program's ``chip_smoke.sage_reference``.
+Nothing here imports the program. ``sage_reference``, the GraphSAGE
+reference that lived here, is ``bench/models/graphsage-mean.py``'s and
+stays importable from this module under its old name.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-PRECISIONS = {"highest": jax.lax.Precision.HIGHEST,
-              "default": jax.lax.Precision.DEFAULT}
+
+@functools.lru_cache(maxsize=None)
+def _jitted(reference):
+    return jax.jit(reference,
+                   static_argnames=("fanouts", "dtype", "precision"))
 
 
-def sage_reference(params, hop_rows, hop_ids, fanouts, *,
-                   dtype=jnp.float32, precision: str = "highest"):
-    """Layered GraphSAGE computed in ``dtype`` at matmul ``precision``."""
-    hp = PRECISIONS[precision]
-    cast = lambda x: jnp.asarray(x, dtype)  # noqa: E731
-    h = [cast(r) for r in hop_rows]
-    masks = [cast(jnp.asarray(i) >= 0) for i in hop_ids]
-    n_layers = len(params["layers"])
-    for layer, p in enumerate(params["layers"]):
-        p = jax.tree.map(cast, p)
-        nxt = []
-        for lvl in range(n_layers - layer):
-            fan = fanouts[lvl]
-            child = h[lvl + 1].reshape(h[lvl].shape[0], fan, -1)
-            m = masks[lvl + 1].reshape(h[lvl].shape[0], fan, 1)
-            agg = (child * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
-            z = (jnp.dot(h[lvl], p["self"]["w"], precision=hp)
-                 + p["self"]["b"]
-                 + jnp.dot(agg, p["neigh"]["w"], precision=hp)
-                 + p["neigh"]["b"])
-            mu = z.mean(-1, keepdims=True)
-            var = ((z - mu) ** 2).mean(-1, keepdims=True)
-            z = (z - mu) / jnp.sqrt(var + 1e-5) * p["ln"]["g"] + p["ln"]["b"]
-            nxt.append(z if layer == n_layers - 1 else jnp.maximum(z, 0.0))
-        h = nxt
-    return h[0]
-
-
-_reference_jit = jax.jit(sage_reference,
-                         static_argnames=("fanouts", "dtype", "precision"))
+def __getattr__(name: str):
+    if name == "sage_reference":
+        from bench.lib import cells
+        return cells.model("graphsage-mean").sage_reference
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def rows_of(feats: np.ndarray, ids: np.ndarray) -> np.ndarray:
@@ -107,23 +88,26 @@ def row_mismatches(hop_rows, hops, feats: np.ndarray) -> int:
                    for r, h in zip(hop_rows, hops)))
 
 
-def output_gap(params, out: np.ndarray, hops, feats: np.ndarray, fanouts, *,
-               precision: str = "highest") -> float:
-    """Largest |served − reference| over the request's output rows."""
-    ref = _reference_jit(params, [rows_of(feats, h) for h in hops],
-                         [np.asarray(h) for h in hops], tuple(fanouts),
-                         precision=precision)
+def output_gap(model, params, out: np.ndarray, hops, feats: np.ndarray,
+               fanouts, *, precision: str = "highest") -> float:
+    """Largest |served − reference| over the request's output rows;
+    ``model`` is the served model's module (``cells.model``)."""
+    ref = _jitted(model.reference)(
+        params, [rows_of(feats, h) for h in hops],
+        [np.asarray(h) for h in hops], tuple(fanouts), dtype=jnp.float32,
+        precision=precision)
     n = out.shape[0]
     return float(np.abs(np.asarray(out, np.float64)
                         - np.asarray(ref[:n], np.float64)).max(initial=0.0))
 
 
-def control_output(params, hops, feats: np.ndarray, fanouts, n: int,
+def control_output(model, params, hops, feats: np.ndarray, fanouts, n: int,
                    dtype=jnp.bfloat16) -> tuple[list, np.ndarray]:
-    """The reference put in the program's place one precision down:
-    the rows it would collect and the output it would serve."""
+    """The model's reference put in the program's place one precision
+    down: the rows it would collect and the output it would serve."""
     rows = [jnp.asarray(rows_of(feats, h), dtype) for h in hops]
-    out = _reference_jit(params, rows, [np.asarray(h) for h in hops],
-                         tuple(fanouts), dtype=dtype, precision="default")
+    out = _jitted(model.reference)(
+        params, rows, [np.asarray(h) for h in hops], tuple(fanouts),
+        dtype=dtype, precision="default")
     return ([np.asarray(r.astype(jnp.float32)) for r in rows],
             np.asarray(out[:n].astype(jnp.float32)))

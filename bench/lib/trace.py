@@ -18,6 +18,8 @@ import glob
 import os
 from collections import defaultdict
 
+import numpy as np
+
 WINDOW = "bench.window"
 # the host span a gap is named after, most specific first
 GAP_ORDER = ("collect", "run.device", "run.host", "generator", "admission")
@@ -82,12 +84,28 @@ def gaps(busy: list[tuple[int, int]], lo: int, hi: int
     return out
 
 
-def name_gap(mid: int, spans: dict[str, list[tuple[int, int]]]) -> str:
+def open_at(intervals, ts: np.ndarray) -> np.ndarray:
+    """For each time in ``ts``: is any interval [s, e) open there. Sorts
+    the starts and the ends once and counts, for each time, how many
+    intervals have started less how many have ended."""
+    iv = np.asarray([(s, e) for s, e in intervals if s < e],
+                    np.int64).reshape(-1, 2)
+    s, e = np.sort(iv[:, 0]), np.sort(iv[:, 1])
+    return (np.searchsorted(s, ts, side="right")
+            - np.searchsorted(e, ts, side="right")) > 0
+
+
+def name_gaps(mids, spans: dict[str, list[tuple[int, int]]]) -> list[str]:
+    """The name of each gap midpoint: the first span of ``GAP_ORDER`` open
+    there, else ``no_request``."""
+    mids = np.asarray(mids, np.int64)
+    names = np.full(mids.shape, "no_request", dtype=object)
+    left = np.ones(mids.shape, bool)
     for name in GAP_ORDER:
-        for s, e in spans.get(name, ()):
-            if s <= mid < e:
-                return name
-    return "no_request"
+        hit = left & open_at(spans.get(name, ()), mids)
+        names[hit] = name
+        left &= ~hit
+    return names.tolist()
 
 
 def reduce(tr: dict, spans: dict[str, list[tuple[int, int]]] | None = None,
@@ -109,8 +127,10 @@ def reduce(tr: dict, spans: dict[str, list[tuple[int, int]]] | None = None,
         if lo <= s < hi:
             calls[name.split("(")[0]].append(d * 1e-9)
     idle: dict[str, int] = defaultdict(int)
-    for s, e in gaps(busy, lo, hi):
-        idle[name_gap((s + e) // 2, spans or {})] += e - s
+    idle_iv = gaps(busy, lo, hi)
+    for (s, e), name in zip(idle_iv, name_gaps(
+            [(s + e) // 2 for s, e in idle_iv], spans or {})):
+        idle[name] += e - s
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa
     return {"busy_s": busy_ns * 1e-9, "window_s": (hi - lo) * 1e-9,
             "op_count": sum(lo <= s < hi for _, s, _ in tr["ops"]),

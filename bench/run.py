@@ -96,7 +96,7 @@ def main(argv=None, *, devices=None, root: str = ROOT) -> int:
 def run_cell(args, cell: dict, cfg: dict, mix: dict, devs, root: str) -> int:
     import jax
 
-    from bench.lib import cells, counts, harness, peaks, trace
+    from bench.lib import cells, harness, peaks, trace
     from bench.lib import traffic as tr
     from repro.launch.serve import use_compile_cache
 
@@ -107,7 +107,6 @@ def run_cell(args, cell: dict, cfg: dict, mix: dict, devs, root: str) -> int:
         f"{use_compile_cache()}")
     peak = peaks.peaks(dev.device_kind) if dev.platform == "tpu" else {}
     rec = harness.Recorder(trace=bool(args.trace))
-    jax.monitoring.register_event_listener(rec.on_compile_event)
     jax.monitoring.register_event_duration_secs_listener(
         rec.on_compile_event)
 
@@ -171,13 +170,14 @@ def run_cell(args, cell: dict, cfg: dict, mix: dict, devs, root: str) -> int:
         cell=cell["name"], config=cfg, traffic=mix, seconds=args.seconds,
         t0=t0, reqs=reqs, spans=dict(rec.spans), counters=counters,
         routed=routed, collect_bytes=collect_bytes, trace=reduced,
-        peaks=peak, flops_per_seed=counts.sage_flops_per_seed(
-            cfg["feat_dim"], cfg["hidden"], cfg["fanouts"]))
+        peaks=peak, flops_per_seed=system.model_mod.flops_per_seed(cfg),
+        compiles=rec.compiles)
 
     chk = cfg["check"]
     numbers, per_exec = harness.check(
-        caps, system.params, (system.indptr, system.indices, system.feats),
-        system.fanouts, chk, chk["precision"])
+        caps, system.model_mod, system.params,
+        (system.indptr, system.indices, system.feats), system.fanouts, chk,
+        chk["precision"])
     n_failed = harness.failed(reqs)
     checks = {
         "never_completed": [n_failed, "<=", 0],
